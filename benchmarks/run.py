@@ -15,6 +15,7 @@ from benchmarks import (bench_faults, bench_gen, bench_planner,
                         bench_world, fig5_emd, fig6_selection, fig7_power,
                         fig8_subproblems, fig9_generation, fig10_noniid,
                         roofline, theorem1)
+from repro.compile_cache import use_compile_cache
 
 MODULES = {
     "fig5": fig5_emd.run,
@@ -46,6 +47,7 @@ def main() -> int:
     ap.add_argument("--quick", action="store_true",
                     help=f"skip the FL-training figures {HEAVY}")
     args = ap.parse_args()
+    use_compile_cache()
 
     keys = list(MODULES)
     if args.only:

@@ -15,6 +15,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs.base import GenFVConfig
 from repro.exp import ExperimentSpec, Sweep
 from repro.fl import RunConfig
@@ -30,6 +31,7 @@ def main():
                     help="repro.sim traffic scenario, or 'legacy' for the "
                          "memoryless per-round fleet sampler")
     args = ap.parse_args()
+    use_compile_cache()
 
     # one declarative grid over the scheme axis; Sweep shares the dataset
     # build across schemes and plans all their rounds in batched dispatches

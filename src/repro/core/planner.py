@@ -8,7 +8,7 @@ Algorithm 3 BCD outer loop on the host: up to `bcd_max_iter x (bw_max_iter
 module ports the whole small-computation scale to ONE jitted XLA program:
 
 * every loop is a `lax.while_loop` with the SAME iteration structure and
-  float-op order as the numpy solvers, run in float64 (`enable_x64`), so
+  float-op order as the numpy solvers, run in float64 (`jax.enable_x64`), so
   the results agree to tight tolerances (tests/test_planner.py pins them);
 * the selected set is padded into the power-of-two bucket scheme shared
   with `fl/fleet.py` (`bucket_size`, floor 4): padded slots carry zero
@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.configs.base import GenFVConfig
 from repro.core import channel, gpu_model
@@ -182,7 +181,7 @@ def _device_consts(c: PlannerConsts) -> PlannerConsts:
     """Device-resident copy of the consts: uploading 21 host scalars per
     dispatch costs ~0.1 ms on CPU, and the runner calls the planner with
     the same config every round."""
-    with enable_x64():
+    with jax.enable_x64(True):
         return PlannerConsts(*(jnp.asarray(v) for v in c))
 
 
@@ -378,7 +377,7 @@ def plan_selected_jax(cfg: GenFVConfig, model_bits: float,
     valid = np.zeros(kp, bool)
     valid[:k] = True
     c = _device_consts(planner_consts(cfg, model_bits, svc, eps))
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _plan_one(c, _pad(consts.t_cp, kp), _pad(consts.e_cp, kp),
                         _pad(consts.b_prime, kp),
                         _pad(consts.phi_max, kp, cfg.phi_min),
@@ -449,7 +448,7 @@ def plan_rounds_batched(cfg: GenFVConfig, fleets: Sequence[Sequence[Vehicle]],
     valid = np.zeros((len(live), kp), bool)
     for row, f in enumerate(live):
         valid[row, :len(idxs[f])] = True
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _plan_many(c, stack(lambda s: s.t_cp), stack(lambda s: s.e_cp),
                          stack(lambda s: s.b_prime),
                          stack(lambda s: s.phi_max, cfg.phi_min),

@@ -375,9 +375,11 @@ class GenFVRunner:
 
     def plan(self, pending: PendingRound) -> RoundPlan:
         """Phase 2: SUBP2-4 resource allocation for one pending round."""
-        # span key mirrors the jax planner's jit cache key (the padded
-        # bucket size) so the first dispatch per bucket tags as "compile"
-        bucket = bucket_size(len(pending.fleet)) if pending.fleet else 0
+        # span key mirrors the jax planner's jit cache key (the selected set
+        # padded to its bucket) so the first dispatch per bucket tags as
+        # "compile"
+        k = int(np.sum(pending.alpha))
+        bucket = bucket_size(k) if k else 0
         key = (self.run.planner, bucket) if self.run.planner == "jax" else None
         # no sync needed: plan_round unpacks to host scalars (self-fencing)
         with self.obs.span("round/plan", key=key, round=pending.t,

@@ -31,6 +31,7 @@ def host_meta() -> Dict[str, Any]:
         "python": platform.python_version(),
         "jax": jax.__version__,
         "backend": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "device_count": jax.device_count(),
         "cpu_count": os.cpu_count(),
     }

@@ -138,6 +138,26 @@ def test_exact_bucket_vs_padded(setup):
     assert _leaves_equal(exact, padded)
 
 
+def test_donating_engine_copies_aliased_aug(setup):
+    """The accelerator default donates the global params. When the aug model
+    is the round-start globals themselves (empty AIGC pool), the engine must
+    copy it rather than pass a donated buffer a second time; the result
+    matches the non-donating dispatch and the passed-in params are
+    consumed."""
+    params, _, datasets, sizes = setup
+    ref_engine = FleetEngine(CFG, H, B, 5e-2, donate=False)
+    bi, bl = _engine_batches(ref_engine, datasets)
+    ref, _ = ref_engine.run(params, bi, bl, data_weights(sizes),
+                            mean_emd(EMDS), params)
+
+    own = jax.tree.map(jnp.copy, params)     # the module fixture stays live
+    engine = FleetEngine(CFG, H, B, 5e-2, donate=True)
+    new, _ = engine.run(own, bi, bl, data_weights(sizes), mean_emd(EMDS),
+                        own)
+    _leaves_allclose(ref, new)
+    assert all(x.is_deleted() for x in jax.tree.leaves(own))
+
+
 def test_engine_rejects_bad_args(setup):
     params, _, datasets, sizes = setup
     engine = FleetEngine(CFG, H, B, 5e-2, donate=False)

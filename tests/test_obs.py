@@ -20,6 +20,7 @@ import os
 import re
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -230,7 +231,8 @@ def test_metrics_artifact_roundtrip(tmp_path):
     assert doc["schema"] == METRICS_SCHEMA
     assert doc["meta"] == {"spec": "unit"}
     assert doc["open_spans"] == 0 and doc["events"] == 4
-    assert {"backend", "jax", "platform"} <= set(doc["host"])
+    assert {"backend", "device_kind", "jax", "platform"} <= set(doc["host"])
+    assert doc["host"]["device_kind"] == jax.devices()[0].device_kind
     names = {c["name"] for c in doc["counters"]}
     assert "planner/rounds" in names
     assert any(d["name"] == "span/round/plan" for d in doc["dists"])
