@@ -60,16 +60,22 @@ def paper_cell(**overrides):
                   train_size=TRAIN_SIZE, rounds=ROUNDS), **overrides)
 
 
-def _phases(obs, t: int) -> str:
-    """One round's obs spans as `name=seconds[c]` (c: first use of the
-    span's compile key)."""
+def _phases(obs, first: int) -> str:
+    """The spans one round emitted (`obs.events` from index `first`) as
+    `name=seconds[c]` (c: first use of the span's compile key)."""
     out = []
-    for ev in obs.events:
-        if ev["ph"] == "X" and ev["tags"].get("round") == t:
+    for ev in obs.events[first:]:
+        if ev["ph"] == "X":
             mark = "c" if ev["stage"] == "compile" else ""
             out.append(f"{ev['name'].removeprefix('round/')}="
                        f"{ev['dur']:.3f}{mark}")
     return " ".join(out)
+
+
+def _copied(obs) -> dict:
+    """Bytes put on the device so far, by site."""
+    return {site: obs.metrics.counter_value("xfer/h2d_bytes", site=site)
+            for site in ("fleet", "eval")}
 
 
 def run_rounds(dataset_fn):
@@ -90,6 +96,7 @@ def run_rounds(dataset_fn):
           f"sampler (priced into eq. 12-13)")
     seen, first = set(), None
     for t in range(ROUNDS):
+        events, copied = len(obs.events), _copied(obs)
         t_start = time.perf_counter()
         pending = runner.begin_round(t)
         plan = runner.plan(pending)
@@ -104,7 +111,11 @@ def run_rounds(dataset_fn):
               f"bucket={bucket} b_gen={log.b_gen} t_bar={log.t_bar:.4f}s "
               f"loss={log.loss:.6f} acc={log.accuracy:.4f} "
               f"wall={wall:.3f}s ({stage})")
-        print(f"  phases[s]: {_phases(obs, t)}")
+        print(f"  phases[s]: {_phases(obs, events)}")
+        now = _copied(obs)
+        print("  h2d_bytes: " + " ".join(
+            f"{site}={now[site] - copied[site]}" for site in now)
+            + f"; gen/pool_bytes={obs.metrics.gauge_value('gen/pool_bytes')}")
         if not (log.loss == log.loss and abs(log.loss) < float("inf")):
             raise RuntimeError(f"round {t}: non-finite loss {log.loss}")
         if not 0.0 <= log.accuracy <= 1.0:

@@ -118,7 +118,7 @@ class Sweep:
         engine = self._engines.get(key)
         if engine is None:
             engine = FleetEngine(cnn, fl.local_steps, fl.batch_size,
-                                 lr=CLIENT_LR, max_bucket=4096)
+                                 lr=CLIENT_LR, max_bucket=4096, obs=self.obs)
             self._engines[key] = engine
         gen = (self.generator_factory(cell)
                if self.generator_factory is not None else None)

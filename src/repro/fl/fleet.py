@@ -41,6 +41,7 @@ from repro.core.emd import aggregate_stacked, aggregate_stacked_guarded, kappas
 # here for existing callers)
 from repro.core.planner import bucket_size  # noqa: F401
 from repro.fl.client import local_sgd_steps
+from repro.obs import NULL_OBS
 
 
 def _fleet_step_impl(cfg, h: int, lr: float, prox_mu: float, global_params,
@@ -98,10 +99,14 @@ class FleetEngine:
 
     One engine per (model cfg, h, batch size, lr); bucketed jit caches live
     in jax's global compilation cache keyed on the static args + shapes.
+    `obs` (a `repro.obs` tracer) times the host stacking, the copy to the
+    device and the fused step as children of the caller's
+    `round/aggregate` span.
     """
 
     def __init__(self, cfg_model, local_steps: int, batch_size: int,
-                 lr: float, max_bucket: int = 64, donate: bool | None = None):
+                 lr: float, max_bucket: int = 64, donate: bool | None = None,
+                 obs=None):
         # max_bucket caps trace size: the fixed-order reduction unrolls
         # O(bucket) adds per leaf, so huge buckets inflate compile time —
         # raise it explicitly for fleets beyond 64 concurrent vehicles
@@ -119,6 +124,7 @@ class FleetEngine:
         self._step_guarded = (_fleet_step_guarded_donated if self.donate
                               else _fleet_step_guarded)
         self._zeros = None  # cached kappa2=0 stand-in for a missing aug model
+        self.obs = obs if obs is not None else NULL_OBS
 
     # -- host-side batch sampling (mirrors client_update's rng protocol) ---
     def sample_batches(self, rng: np.random.Generator, images, labels):
@@ -149,13 +155,6 @@ class FleetEngine:
         if kb < k:
             raise ValueError(f"bucket {kb} smaller than fleet {k}")
 
-        imgs = np.stack([np.asarray(x, np.float32) for x in imgs_list])
-        labels = np.stack([np.asarray(x, np.int32) for x in labels_list])
-        if kb > k:
-            pad = ((0, kb - k),) + ((0, 0),) * (imgs.ndim - 1)
-            imgs = np.pad(imgs, pad)
-            labels = np.pad(labels, ((0, kb - k),) + ((0, 0),) * (labels.ndim - 1))
-
         if aug_params is None:
             emd_bar = 0.0              # kappa2 = 0: pure weighted FedAvg
             if self._zeros is None:
@@ -168,15 +167,31 @@ class FleetEngine:
             aug_params = jax.tree.map(jnp.copy, aug_params)
         k1, k2 = kappas(emd_bar)
 
-        weights = np.zeros(kb, np.float32)
-        weights[:k] = k1 * np.asarray(rhos, np.float64)
+        obs = self.obs
+        with obs.span("round/aggregate/stack"):
+            imgs = np.stack([np.asarray(x, np.float32) for x in imgs_list])
+            labels = np.stack([np.asarray(x, np.int32) for x in labels_list])
+            if kb > k:
+                pad = ((0, kb - k),) + ((0, 0),) * (imgs.ndim - 1)
+                imgs = np.pad(imgs, pad)
+                labels = np.pad(labels,
+                                ((0, kb - k),) + ((0, 0),) * (labels.ndim - 1))
+            weights = np.zeros(kb, np.float32)
+            weights[:k] = k1 * np.asarray(rhos, np.float64)
+        with obs.span("round/aggregate/put") as sp:
+            batch = jax.device_put((imgs, labels, weights))
+            sp.sync = batch
+        if obs.enabled:
+            obs.count("xfer/h2d_bytes", sum(a.nbytes for a in batch),
+                      site="fleet")
 
-        args = (self.cfg, self.h, self.lr, float(prox_mu), global_params,
-                jnp.asarray(imgs), jnp.asarray(labels), jnp.asarray(weights),
-                aug_params, jnp.float32(k2))
-        if guard:
-            new_params, losses, finite = self._step_guarded(*args)
-            return (new_params, np.asarray(losses[:k]).mean(axis=1),
-                    np.asarray(finite[:k]))
-        new_params, losses = self._step(*args)
-        return new_params, np.asarray(losses[:k]).mean(axis=1)
+        # self-fencing: the loss readback waits for the dispatch
+        with obs.span("round/aggregate/step", key=(kb, guard)):
+            args = (self.cfg, self.h, self.lr, float(prox_mu), global_params,
+                    *batch, aug_params, jnp.float32(k2))
+            if guard:
+                new_params, losses, finite = self._step_guarded(*args)
+                return (new_params, np.asarray(losses[:k]).mean(axis=1),
+                        np.asarray(finite[:k]))
+            new_params, losses = self._step(*args)
+            return new_params, np.asarray(losses[:k]).mean(axis=1)

@@ -285,7 +285,8 @@ class GenFVRunner:
                                                   run.sampler_steps)
             else:
                 gen = OracleGenerator(run.dataset)
-        self.server = GenFVServer(self.cnn_cfg, params, gen, self.rng)
+        self.server = GenFVServer(self.cnn_cfg, params, gen, self.rng,
+                                  obs=self.obs)
         # max_bucket at the hard ceiling: fleet size is Poisson(num_vehicles),
         # so K can exceed the engine's conservative default cap; buckets
         # compile lazily, an unused headroom costs nothing. An injected
@@ -305,7 +306,7 @@ class GenFVRunner:
         else:
             self.engine = FleetEngine(self.cnn_cfg, self.cfg.local_steps,
                                       self.cfg.batch_size, lr=CLIENT_LR,
-                                      max_bucket=4096)
+                                      max_bucket=4096, obs=self.obs)
         self.classes = classes
         self.b_prev = 0
         # -- fault tolerance (tentpole; all dormant when spec is None) -----
@@ -319,10 +320,11 @@ class GenFVRunner:
         self.logs: List[RoundLog] = []
         self.next_round = 0
         cfg_cnn = self.cnn_cfg
-        self._eval = jax.jit(
-            lambda p, x, y: jnp.mean(
-                (jnp.argmax(cnn_forward(p, cfg_cnn, x), -1) == y)
-                .astype(jnp.float32)))
+
+        def _accuracy(p, x, y):
+            return jnp.mean((jnp.argmax(cnn_forward(p, cfg_cnn, x), -1) == y)
+                            .astype(jnp.float32))
+        self._eval = jax.jit(_accuracy)
 
     # ------------------------------------------------------------------
     def _alpha(self, fleet, round_idx: int) -> np.ndarray:
@@ -501,15 +503,16 @@ class GenFVRunner:
         loss = 0.0
         if use_aigc:
             with self.obs.span("round/generate", round=t,
-                               b_gen=plan.b_gen) as sp:
+                               b_gen=plan.b_gen):
                 counts = label_schedule(
                     plan.b_gen if use_fl else cfg.gen_batch * 4,
                     self.classes)
                 self.server.generate(counts, round_idx=t)
-                aug, aug_loss = self.server.train_augmented(
-                    cfg.local_steps * cfg.rsu_steps_factor, cfg.batch_size,
-                    lr=CLIENT_LR)
-                sp.sync = aug
+                with self.obs.span("round/generate/train") as sp:
+                    aug, aug_loss = self.server.train_augmented(
+                        cfg.local_steps * cfg.rsu_steps_factor,
+                        cfg.batch_size, lr=CLIENT_LR)
+                    sp.sync = aug
             if not use_fl:
                 loss = aug_loss
 
@@ -699,8 +702,13 @@ class GenFVRunner:
 
         # float() forces the device value: the eval span self-fences
         with self.obs.span("round/eval", round=t):
-            acc = float(self._eval(self.server.params, self.test_imgs,
-                                   self.test_labels))
+            with self.obs.span("round/eval/put") as sp:
+                test = jax.device_put((self.test_imgs, self.test_labels))
+                sp.sync = test
+            if self.obs.enabled:
+                self.obs.count("xfer/h2d_bytes",
+                               test[0].nbytes + test[1].nbytes, site="eval")
+            acc = float(self._eval(self.server.params, *test))
         log = RoundLog(t, n_trained, plan.t_bar, plan.b_gen, k2,
                        emd_bar, float(loss), acc, dropped, late, rejected,
                        stale_merged, stale_dropped, float(t_round),

@@ -10,11 +10,13 @@ import numpy as np
 
 from repro.core.emd import aggregate, data_weights, kappas, mean_emd
 from repro.fl.client import client_update
+from repro.obs import NULL_OBS
 
 
 class GenFVServer:
-    def __init__(self, cfg_model, global_params, generator, rng):
+    def __init__(self, cfg_model, global_params, generator, rng, obs=None):
         self.cfg_model = cfg_model
+        self.obs = obs if obs is not None else NULL_OBS
         self.params = global_params
         self.generator = generator
         self.rng = rng
@@ -41,12 +43,18 @@ class GenFVServer:
                                            round_idx=round_idx)
         else:
             imgs = self.generator.generate(labels, self.rng)
-        if self.pool_imgs is None:
-            self.pool_imgs, self.pool_labels = imgs, labels.astype(np.int32)
-        else:
-            self.pool_imgs = np.concatenate([self.pool_imgs, imgs])
-            self.pool_labels = np.concatenate(
-                [self.pool_labels, labels.astype(np.int32)])
+        obs = self.obs
+        with obs.span("round/generate/pool"):
+            if self.pool_imgs is None:
+                self.pool_imgs = imgs
+                self.pool_labels = labels.astype(np.int32)
+            else:
+                self.pool_imgs = np.concatenate([self.pool_imgs, imgs])
+                self.pool_labels = np.concatenate(
+                    [self.pool_labels, labels.astype(np.int32)])
+        if obs.enabled:
+            obs.gauge("gen/pool_bytes",
+                      self.pool_imgs.nbytes + self.pool_labels.nbytes)
         return len(labels)
 
     def train_augmented(self, h: int, batch_size: int, lr: float):

@@ -10,9 +10,9 @@ Three pieces (DESIGN.md §Observability):
 * `metrics.MetricsRegistry` — counters / gauges / distributions fed by what
   the pipeline already computes (planner convergence, bucket padding waste,
   the fault ledger, realized-vs-planned round delay, sweep cache hits).
-* `sinks` — a JSONL event log, a Chrome/Perfetto ``trace.json`` exporter,
-  and the versioned ``repro.obs/metrics/v1`` artifact written alongside the
-  `repro.exp` outputs under ``artifacts/``.
+* `sinks` — a Chrome/Perfetto ``trace.json`` exporter and the versioned
+  ``repro.obs/metrics/v1`` artifact written alongside the `repro.exp`
+  outputs under ``artifacts/``.
 
 The hard invariant: the disabled path (`NULL_OBS`) is a no-op that never
 touches RNG streams or jitted programs, and the ENABLED path only reads —

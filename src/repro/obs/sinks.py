@@ -1,5 +1,5 @@
-"""Obs sinks: JSONL event log, Chrome/Perfetto trace export, and the
-versioned ``repro.obs/metrics/v1`` artifact.
+"""Obs sinks: Chrome/Perfetto trace export and the versioned
+``repro.obs/metrics/v1`` artifact.
 
 The metrics artifact lives alongside the `repro.exp` outputs (default
 ``artifacts/``, override via ``REPRO_ARTIFACTS``) as
@@ -84,21 +84,6 @@ def load_metrics_artifact(path: str) -> Dict[str, Any]:
 def list_metrics_artifacts(directory: str | None = None) -> List[str]:
     d = directory or os.environ.get("REPRO_ARTIFACTS", "artifacts")
     return sorted(glob.glob(os.path.join(d, "*.metrics.json")))
-
-
-# ---------------------------------------------------------------------------
-# JSONL event log.
-# ---------------------------------------------------------------------------
-def write_jsonl(obs, path: str) -> str:
-    """One JSON object per line: a header record, then every span/instant
-    event in emission order."""
-    with open(path, "w") as f:
-        f.write(json.dumps({"schema": f"{SCHEMA_PREFIX}/events/v1",
-                            "meta": obs.meta, "host": host_meta()},
-                           sort_keys=True) + "\n")
-        for ev in obs.events:
-            f.write(json.dumps(ev, sort_keys=True) + "\n")
-    return path
 
 
 # ---------------------------------------------------------------------------

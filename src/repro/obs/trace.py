@@ -217,10 +217,6 @@ class Obs:
         from repro.obs.sinks import write_trace
         return write_trace(self, path)
 
-    def write_jsonl(self, path: str) -> str:
-        from repro.obs.sinks import write_jsonl
-        return write_jsonl(self, path)
-
 
 class _Tagged:
     """Tag-scoped view of an `Obs` (same surface, extra tags merged in)."""

@@ -158,6 +158,31 @@ def test_donating_engine_copies_aliased_aug(setup):
     assert all(x.is_deleted() for x in jax.tree.leaves(own))
 
 
+@pytest.mark.parametrize("bucket", [4, 8])
+def test_traced_engine_spans_bytes_and_parity(setup, bucket):
+    """With a tracer attached the engine times its host stacking, its copy
+    and its fused step as three spans, counts the bytes it put on the
+    device (the padded [bucket,h,B] float32 16x16x3 images and int32
+    labels, a float32 weight per slot), and returns bitwise what the
+    untraced engine returns."""
+    from repro.obs import Obs
+    params, aug, datasets, sizes = setup
+    plain = FleetEngine(CFG, H, B, 5e-2, donate=False)
+    bi, bl = _engine_batches(plain, datasets)
+    obs = Obs()
+    traced = FleetEngine(CFG, H, B, 5e-2, donate=False, obs=obs)
+    args = (bi, bl, data_weights(sizes), mean_emd(EMDS), aug)
+    want, want_loss = plain.run(params, *args, bucket=bucket)
+    got, got_loss = traced.run(params, *args, bucket=bucket)
+    assert _leaves_equal(want, got)
+    np.testing.assert_array_equal(want_loss, got_loss)
+    assert [e["name"] for e in obs.events] == [
+        "round/aggregate/stack", "round/aggregate/put",
+        "round/aggregate/step"]
+    assert obs.metrics.counter_value("xfer/h2d_bytes", site="fleet") == \
+        bucket * H * B * (16 * 16 * 3 * 4 + 4) + bucket * 4
+
+
 def test_engine_rejects_bad_args(setup):
     params, _, datasets, sizes = setup
     engine = FleetEngine(CFG, H, B, 5e-2, donate=False)

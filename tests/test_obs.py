@@ -208,7 +208,7 @@ def test_null_obs_overhead_smoke():
 
 
 # ---------------------------------------------------------------------------
-# Sinks: metrics artifact, JSONL, Chrome/Perfetto trace.
+# Sinks: metrics artifact, Chrome/Perfetto trace.
 # ---------------------------------------------------------------------------
 def _sample_obs() -> Obs:
     obs = Obs(clock=FakeClock(), meta={"spec": "unit"})
@@ -246,15 +246,6 @@ def test_metrics_artifact_schema_guard(tmp_path):
     with pytest.raises(ValueError, match="schema"):
         save_metrics_artifact({"schema": "wrong"}, "x",
                               directory=str(tmp_path))
-
-
-def test_write_jsonl(tmp_path):
-    obs = _sample_obs()
-    path = obs.write_jsonl(str(tmp_path / "events.jsonl"))
-    lines = [json.loads(l) for l in open(path)]
-    assert lines[0]["schema"] == "repro.obs/events/v1"
-    assert len(lines) == 1 + len(obs.events)
-    assert {l["ph"] for l in lines[1:]} == {"X", "i"}
 
 
 def test_trace_schema(tmp_path):
@@ -319,11 +310,15 @@ def test_runner_obs_bitwise_no_perturbation(planner, faults):
     """The hard invariant: an attached tracer only *reads* host values, so
     every RoundLog field — including float curves — is bitwise identical
     to the NULL_OBS run, on both planner backends, faulted or not."""
-    _, traced = _traced(planner, faults)
+    obs, traced = _traced(planner, faults)
     plain = GenFVRunner(_run_cfg(planner, faults), fl_cfg=FAST_CFG).train()
     assert len(plain.logs) == FAST["rounds"]
     for a, b in zip(plain.logs, traced.logs):
         assert a == b                           # every field, bitwise
+    # the traced run went through a vectorized genfv round that holds
+    # every child span of the dispatch, the RSU step and eval
+    names = {e["name"] for e in obs.events if e["ph"] == "X"}
+    assert set(CHILD_SPANS) <= names, set(CHILD_SPANS) - names
 
 
 def test_runner_obs_bitwise_ddpm_generate_path(monkeypatch, tmp_path):
@@ -359,6 +354,65 @@ def test_runner_obs_bitwise_ddpm_generate_path(monkeypatch, tmp_path):
     assert spans and sum(d["n"] for d in spans) == gen_rounds
     assert obs.metrics.counter_value("gen/images") == \
         sum(int(l.b_gen) for l in traced.logs)
+
+
+#: child span -> its parent: the host stacking, the copy and the fused
+#: step inside the aggregation; the pool append and the RSU's training
+#: inside generation; the test set's copy inside eval
+CHILD_SPANS = {"round/aggregate/stack": "round/aggregate",
+               "round/aggregate/put": "round/aggregate",
+               "round/aggregate/step": "round/aggregate",
+               "round/generate/pool": "round/generate",
+               "round/generate/train": "round/generate",
+               "round/eval/put": "round/eval"}
+
+
+def test_child_spans_nest_inside_their_parents():
+    obs, res = _traced("jax", None)
+    spans = [e for e in obs.events if e["ph"] == "X"]
+    parents = {p: [e for e in spans if e["name"] == p]
+               for p in set(CHILD_SPANS.values())}
+    inside = {id(e): [] for ps in parents.values() for e in ps}
+    for e in spans:
+        parent = CHILD_SPANS.get(e["name"])
+        if parent is None:
+            continue
+        host = [p for p in parents[parent] if p["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= p["ts"] + p["dur"]]
+        assert len(host) == 1, e
+        inside[id(host[0])].append(e)
+    for ps in parents.values():
+        for p in ps:
+            assert sum(c["dur"] for c in inside[id(p)]) <= p["dur"]
+    # one of each child per round that dispatched, generated or evaluated
+    count = {n: sum(1 for e in spans if e["name"] == n) for n in CHILD_SPANS}
+    fleet_rounds = sum(1 for l in res.logs if l.selected)
+    gen_rounds = sum(1 for l in res.logs if l.b_gen)
+    assert count == {"round/aggregate/stack": fleet_rounds,
+                     "round/aggregate/put": fleet_rounds,
+                     "round/aggregate/step": fleet_rounds,
+                     "round/generate/pool": gen_rounds,
+                     "round/generate/train": FAST["rounds"],
+                     "round/eval/put": FAST["rounds"]}
+
+
+def test_runner_counts_bytes_copied_to_the_device():
+    """xfer/h2d_bytes: the padded fleet batch (float32 32x32x3 images and
+    int32 labels per slot and step, a float32 weight per slot) and the
+    test set; gen/pool_bytes: the RSU's pool after the last append."""
+    from repro.fl.fleet import bucket_size
+    obs, res = _traced("jax", None)
+    h, b = FAST_CFG.local_steps, FAST_CFG.batch_size
+    image = 32 * 32 * 3 * 4
+    fleet = sum(bucket_size(l.selected) * (h * b * (image + 4) + 4)
+                for l in res.logs if l.selected)
+    m = obs.metrics
+    assert fleet > 0
+    assert m.counter_value("xfer/h2d_bytes", site="fleet") == fleet
+    assert m.counter_value("xfer/h2d_bytes", site="eval") == \
+        FAST["rounds"] * FAST["test_size"] * (image + 4)
+    assert m.gauge_value("gen/pool_bytes") == \
+        sum(int(l.b_gen) for l in res.logs) * (image + 4)
 
 
 def test_roundlog_carries_planner_convergence():
@@ -417,7 +471,6 @@ def test_runner_trace_emission(tmp_path):
     stages = {e["args"].get("stage") for e in doc["traceEvents"]
               if e["ph"] == "X"}
     assert {"compile", "execute"} <= stages
-    obs.write_jsonl(str(tmp_path / "events.jsonl"))
 
 
 # ---------------------------------------------------------------------------
