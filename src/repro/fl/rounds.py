@@ -883,10 +883,9 @@ class GenFVRunner:
                             else float(logs[n][i])) for n in names})
             for i in range(len(logs["round"]))]
         pool = state["pool"]
-        self.server.pool_imgs = (np.asarray(pool["imgs"], np.float32)
-                                 if pool else None)
-        self.server.pool_labels = (np.asarray(pool["labels"], np.int32)
-                                   if pool else None)
+        self.server.set_pool(
+            np.asarray(pool["imgs"], np.float32) if pool else None,
+            np.asarray(pool["labels"], np.int32) if pool else None)
         if self.world is not None:
             w = state["world"]
             if not w:

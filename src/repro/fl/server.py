@@ -20,8 +20,11 @@ class GenFVServer:
         self.params = global_params
         self.generator = generator
         self.rng = rng
-        self.pool_imgs: np.ndarray | None = None   # accumulated AIGC data
-        self.pool_labels: np.ndarray | None = None
+        # the accumulated AIGC pool: the first `_pool_n` rows of buffers
+        # that grow geometrically (`pool_imgs` / `pool_labels` view them)
+        self._pool_imgs: np.ndarray | None = None
+        self._pool_labels: np.ndarray | None = None
+        self._pool_n = 0
         # round-keyed generators (gen/service.py) take a round_idx kwarg;
         # bare `generate(labels, rng)` generators (third-party factories)
         # must keep working, so detect once here instead of try/except on
@@ -32,6 +35,54 @@ class GenFVServer:
             self._gen_round_kw = "round_idx" in sig.parameters
         except (TypeError, ValueError):
             self._gen_round_kw = False
+
+    # ---- the generated pool -----------------------------------------------
+    @property
+    def pool_imgs(self) -> np.ndarray | None:
+        """The images generated so far (None while empty). A view that
+        later appends never write: they fill rows past it or move the
+        pool to a new buffer."""
+        return self._pool_imgs[:self._pool_n] if self._pool_n else None
+
+    @property
+    def pool_labels(self) -> np.ndarray | None:
+        return self._pool_labels[:self._pool_n] if self._pool_n else None
+
+    def set_pool(self, imgs: np.ndarray | None,
+                 labels: np.ndarray | None) -> None:
+        """Replace the pool by a copy of `imgs` / `labels` (None: empty)."""
+        self._pool_imgs = self._pool_labels = None
+        self._pool_n = 0
+        if imgs is not None:
+            self._pool_append(imgs, labels)
+
+    def _pool_append(self, imgs: np.ndarray, labels: np.ndarray):
+        """Write a batch after the filled rows. A full buffer is replaced
+        by one of twice the rows needed, into which the filled rows are
+        copied once, so each image is written O(1) times on average.
+        Returns the rows written (a regrowth's copy included) and whether
+        the buffers grew."""
+        n, b = self._pool_n, len(labels)
+        buf = self._pool_imgs
+        if buf is not None and (imgs.dtype, imgs.shape[1:]) != \
+                (buf.dtype, buf.shape[1:]):
+            raise ValueError(
+                f"the pool holds {buf.dtype} images of {buf.shape[1:]}, "
+                f"not {imgs.dtype} of {imgs.shape[1:]}")
+        written, grew = b, False
+        if buf is None or n + b > len(buf):
+            cap = 2 * (n + b)
+            grown = np.empty((cap,) + imgs.shape[1:], imgs.dtype)
+            grown_labels = np.empty(cap, np.int32)
+            if n:
+                grown[:n] = buf[:n]
+                grown_labels[:n] = self._pool_labels[:n]
+                written, grew = n + b, True
+            self._pool_imgs, self._pool_labels = grown, grown_labels
+        self._pool_imgs[n:n + b] = imgs
+        self._pool_labels[n:n + b] = labels
+        self._pool_n = n + b
+        return written, grew
 
     # ---- model augmentation (step 5) -------------------------------------
     def generate(self, label_counts: np.ndarray, round_idx: int = 0):
@@ -45,16 +96,13 @@ class GenFVServer:
             imgs = self.generator.generate(labels, self.rng)
         obs = self.obs
         with obs.span("round/generate/pool"):
-            if self.pool_imgs is None:
-                self.pool_imgs = imgs
-                self.pool_labels = labels.astype(np.int32)
-            else:
-                self.pool_imgs = np.concatenate([self.pool_imgs, imgs])
-                self.pool_labels = np.concatenate(
-                    [self.pool_labels, labels.astype(np.int32)])
+            written, grew = self._pool_append(imgs, labels.astype(np.int32))
         if obs.enabled:
-            obs.gauge("gen/pool_bytes",
-                      self.pool_imgs.nbytes + self.pool_labels.nbytes)
+            row = self._pool_imgs[0].nbytes + self._pool_labels.itemsize
+            obs.count("gen/pool_copy_bytes", written * row)
+            obs.count("gen/pool_grows", int(grew))
+            obs.gauge("gen/pool_bytes", self._pool_n * row)
+            obs.gauge("gen/pool_capacity_bytes", len(self._pool_imgs) * row)
         return len(labels)
 
     def train_augmented(self, h: int, batch_size: int, lr: float):

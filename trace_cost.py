@@ -11,8 +11,10 @@ process (default: spans, profiled three times, host0, device_off):
 * spans: the program's tracer attached and no profiler, for the
   benchmark's `run_seconds`: the time of each round, each span's seconds
   in each round, the bytes each round copied to the device
-  (`xfer/h2d_bytes` by site) and the RSU pool's size after it
-  (`gen/pool_bytes`);
+  (`xfer/h2d_bytes` by site), the RSU pool's size after it
+  (`gen/pool_bytes`), and the bytes the round's append wrote and whether
+  the pool's buffers grew (`gen/pool_copy_bytes`, `gen/pool_grows`; 0 in
+  a program without them);
 * profiled: `run_cell.py --trace 1` with a `PROFILED_SECONDS` window (its
   result line, and the time of each round);
 * host0, device_off: the first five window rounds under the profiler
@@ -64,10 +66,13 @@ def median(xs):
 
 def span_table(rounds, names):
     """Per span: median seconds a round over the first KEPT rounds and
-    over all rounds (0 where a round lacks the span)."""
+    over all rounds, and the mean over all rounds (0 where a round lacks
+    the span)."""
     return {n: {"kept": median([r["spans"].get(n, 0.0)
                                 for r in rounds[:KEPT]]),
-                "window": median([r["spans"].get(n, 0.0) for r in rounds])}
+                "window": median([r["spans"].get(n, 0.0) for r in rounds]),
+                "mean": statistics.mean([r["spans"].get(n, 0.0)
+                                         for r in rounds])}
             for n in names}
 
 
@@ -83,18 +88,24 @@ def spans(cfgd, celld, seed: int, seconds: float) -> dict:
         return {site: m.counter_value("xfer/h2d_bytes", site=site)
                 for site in ("fleet", "eval")}
 
+    def pool():
+        return (m.counter_value("gen/pool_copy_bytes"),
+                m.counter_value("gen/pool_grows"))
+
     def traced_round(recorder=None):
-        first, before = len(obs.events), h2d()
+        first, before, pool_before = len(obs.events), h2d(), pool()
         lg, dt = inner(recorder)
         per = {}
         for ev in obs.events[first:]:
             if ev["ph"] == "X":
                 per[ev["name"]] = per.get(ev["name"], 0.0) + ev["dur"]
         after = h2d()
+        copied, grew = (a - b for a, b in zip(pool(), pool_before))
         rounds.append({"round": lg.round, "k": lg.selected,
                        "b_gen": lg.b_gen, "s": dt, "spans": per,
                        "h2d_bytes": {k: after[k] - before[k] for k in after},
-                       "pool_bytes": m.gauge_value("gen/pool_bytes")})
+                       "pool_bytes": m.gauge_value("gen/pool_bytes"),
+                       "pool_copy_bytes": copied, "pool_grows": grew})
         return lg, dt
 
     s.round = traced_round
@@ -183,10 +194,14 @@ def summary(runs) -> None:
                 f"; {r['window_compiles']} compilations in the window")
             for n, v in r["spans"].items():
                 log(f"  {n}: median {v['kept']:.5f}s (rounds 2-6), "
-                    f"{v['window']:.5f}s (window)")
+                    f"{v['window']:.5f}s (window), mean {v['mean']:.5f}s "
+                    f"(window)")
             pool = [x["pool_bytes"] for x in r["rounds"]]
+            copied = sum(x["pool_copy_bytes"] for x in r["rounds"])
+            grows = sum(x["pool_grows"] for x in r["rounds"])
             log(f"  gen/pool_bytes first {pool[0]} last {pool[-1]} "
-                f"({len(pool)} rounds)")
+                f"({len(pool)} rounds); the window's gen/pool_copy_bytes "
+                f"{copied}, gen/pool_grows {grows}")
         elif name == "profiled":
             res = r["result"]
             metrics = {k: round(v["value"], 6)
