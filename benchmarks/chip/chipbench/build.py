@@ -16,52 +16,52 @@ from functools import partial
 import jax
 import numpy as np
 
-from chipbench import data
+from chipbench import cells, data
+
+
+def _layout(tree):
+    return jax.tree.map(lambda a: (a.shape, a.dtype), tree)
 
 
 def build_runner(cfgd: dict, celld: dict, seed: int, obs=None):
-    """Returns (runner, unet params). Weights come from the seed and are
-    handed to the runner in place of its own initialisation."""
+    """Returns (runner, generator params). Weights come from the seed and
+    are handed to the runner in place of its own initialisation. The
+    config's `model.arch` and `generator.arch` name the files under
+    `archs/` that make the weights and build the program's objects; each
+    object's parameter layout must be that of the seeded weights."""
     from repro.configs.genfv_cifar import genfv_config
     from repro.fl import GenFVRunner, RunConfig
     from repro.gen.calib import MeasuredService
-    from repro.gen.service import BatchedDDPMGenerator, runner_ddpm
 
     run_f, model, gen = cfgd["run"], cfgd["model"], cfgd["generator"]
+    classes = model["num_classes"]
+    client_arch, gen_arch = cells.arch(model["arch"]), cells.arch(gen["arch"])
     strategy = celld["strategy"]
     run = RunConfig(dataset=run_f["dataset"], alpha=celld["alpha"],
                     strategy=strategy, train_size=run_f["train_size"],
                     test_size=run_f["test_size"],
-                    width_mult=model["width_mult"],
                     seed=int(celld["traffic_seed"]),
                     vectorized=True, scenario=celld["scenario"],
-                    planner="jax", generator="ddpm",
-                    sampler_steps=celld["sampler_steps"], obs=obs,
-                    rounds=1 << 30)
+                    planner="jax", sampler_steps=celld["sampler_steps"],
+                    obs=obs, rounds=1 << 30,
+                    **client_arch.runner_fields(model))
     fl_cfg = genfv_config(run_f["dataset"], celld["alpha"], **cfgd["fl"])
-    cnn_params, unet_params = data.make_weights(
-        seed, model["num_classes"], model["width_mult"], gen["base_width"])
-    ddpm = runner_ddpm(model["num_classes"])
-    if (ddpm.timesteps, ddpm.base_width) != (gen["timesteps"],
-                                              gen["base_width"]):
-        raise ValueError(f"the program serves a DDPM of {ddpm.timesteps} "
-                         f"steps at base width {ddpm.base_width}; the "
-                         f"config asks for {gen}")
-    generator = BatchedDDPMGenerator(unet_params, ddpm, seed=int(seed),
-                                     sampler_steps=celld["sampler_steps"],
-                                     obs=obs)
+    client_params, gen_params = data.make_weights(seed, classes, model, gen)
+    if _layout(gen_arch.program_layout(classes, gen)) != _layout(gen_params):
+        raise ValueError(f"the seeded {gen['arch']} weights do not match the "
+                         f"layout of the program's generator for {gen}")
+    generator = gen_arch.serve(gen_params, classes, gen, seed=int(seed),
+                               sampler_steps=celld["sampler_steps"], obs=obs)
     svc = MeasuredService(t_image=float(gen["t_image"]),
                           steps=int(gen["t_image_steps"]))
     runner = GenFVRunner(run, fl_cfg=fl_cfg, generator=generator,
                          dataset_fn=partial(data.make_dataset,
                                             pixel_seed=int(seed)), svc=svc)
-    shapes = jax.tree.map(lambda a: (a.shape, a.dtype), runner.server.params)
-    ours = jax.tree.map(lambda a: (a.shape, a.dtype), cnn_params)
-    if shapes != ours:
-        raise ValueError("the seeded ResNet-18 does not match the round "
-                         "loop's parameter layout")
-    runner.server.params = cnn_params
-    return runner, unet_params
+    if _layout(runner.server.params) != _layout(client_params):
+        raise ValueError(f"the seeded {model['arch']} weights do not match "
+                         f"the round loop's parameter layout")
+    runner.server.params = client_params
+    return runner, gen_params
 
 
 def cfg_dict(runner) -> dict:

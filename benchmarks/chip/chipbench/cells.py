@@ -5,6 +5,10 @@ Layout under the benchmark directory (later cells add files, never edit):
   cells/<workload>.json   the traffic: strategy, scenario, alpha, sampler
                           steps, warm lists, limits of the comparison
   metrics/<metric>.py     one reader per per-layer metric
+  archs/<arch>.py         one file per architecture a config names
+                          (`model.arch`, `generator.arch`): seeded weights,
+                          the program's object, the plain reference, and
+                          parameter, FLOP and byte counts
 and BENCHMARK.json at the root of the checkout lists which metrics each
 cell reports.
 """
@@ -12,6 +16,8 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import re
+from functools import lru_cache
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parents[1]          # benchmarks/chip
@@ -34,8 +40,12 @@ def cell(name: str) -> dict:
 
 
 def config(name: str) -> dict:
+    """The config file; an architecture it names that has no file under
+    archs/ fails here, before any device work."""
     c = load_json(HERE / "configs" / f"{name}.json")
     c["name"] = name
+    arch(c["model"]["arch"])
+    arch(c["generator"]["arch"])
     return c
 
 
@@ -46,11 +56,23 @@ def cell_metrics(bench: dict, workload: str, trace: bool) -> list:
             if "workloads" not in m or workload in m["workloads"]]
 
 
-def reader(metric: str):
-    """metrics/<metric>.py's `read(ctx)`."""
-    path = HERE / "metrics" / f"{metric}.py"
+def _module(kind: str, name: str):
+    path = HERE / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no file {path} for {kind[:-1]} {name!r}")
     spec = importlib.util.spec_from_file_location(
-        "metric_" + metric.replace(".", "_"), path)
+        f"{kind[:-1]}_" + re.sub(r"\W", "_", name), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+@lru_cache(maxsize=None)
+def arch(name: str):
+    """archs/<name>.py, loaded once per process."""
+    return _module("archs", name)
+
+
+def reader(metric: str):
+    """metrics/<metric>.py's `read(ctx)`."""
+    return _module("metrics", metric).read

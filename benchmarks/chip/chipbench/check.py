@@ -1,6 +1,7 @@
 """Decides `correct`: the kept rounds of the window against the plain
-references (`reference/`), layer by layer, each layer fed the inputs the
-program's layer was fed.
+references (`reference/`, reached through the architecture files the
+config names), layer by layer, each layer fed the inputs the program's
+layer was fed.
 
 Numbers compared (the worst over the kept rounds), each against the limit
 in the cell file:
@@ -31,8 +32,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from reference import planner as ref_plan
-from reference import resnet as ref_resnet
-from reference import unet as ref_unet
 
 GENERATING = ("genfv", "aigc_only")
 
@@ -89,9 +88,9 @@ def reference_outputs(rec, ctx, dtype=jnp.float32):
     params_in = rec["params_in"]
     if "gen" in rec:
         labels, _, r = rec["gen"]
-        out["images"] = ref_unet.sample(ctx["unet_params"], ctx["seed"], r,
-                                        labels, ctx["timesteps"],
-                                        ctx["sampler_steps"], dtype)
+        out["images"] = ctx["generator"].sample(
+            ctx["gen_params"], ctx["gen_spec"], ctx["seed"], r, labels,
+            ctx["sampler_steps"], dtype)
     if "aug" in rec:
         a = rec["aug"]
         imgs, labels = a["pool"]
@@ -104,8 +103,8 @@ def reference_outputs(rec, ctx, dtype=jnp.float32):
             g = np.random.Generator(np.random.PCG64())
             g.bit_generator.state = a["rng_state"]
             idx = g.integers(0, len(labels), size=(a["h"], a["batch"]))
-            p, losses = ref_resnet.local_sgd(params_in, imgs[idx],
-                                             labels[idx], a["lr"], dtype)
+            p, losses = ctx["client"].local_sgd(params_in, imgs[idx],
+                                                labels[idx], a["lr"], dtype)
             out["aug"] = (_host(p), float(np.mean(losses)))
     if "fleet" in rec:
         f = rec["fleet"]
@@ -115,7 +114,7 @@ def reference_outputs(rec, ctx, dtype=jnp.float32):
                            params_in)
         losses = []
         for rho, bi, bl in zip(f["rhos"], f["imgs"], f["labels"]):
-            p, ls = ref_resnet.local_sgd(params_in, bi, bl, f["lr"], dtype)
+            p, ls = ctx["client"].local_sgd(params_in, bi, bl, f["lr"], dtype)
             losses.append(float(np.mean(ls)))
             acc = jax.tree.map(lambda s, x: s + (1 - k2) * rho * np.asarray(
                 x, np.float64), acc, p)
@@ -124,7 +123,7 @@ def reference_outputs(rec, ctx, dtype=jnp.float32):
                 x, np.float64), acc, f["aug"])
         out["fleet"] = (acc, losses)
     with jax.default_matmul_precision("highest"):
-        out["acc"] = float(ref_resnet.accuracy(
+        out["acc"] = float(ctx["client"].accuracy(
             rec["params_out"], jnp.asarray(ctx["test"][0]),
             jnp.asarray(ctx["test"][1]), dtype=dtype))
     return out
@@ -156,8 +155,11 @@ def compare(rec, got, ref, ctx):
         n["plan_alpha"] = float(np.sum(np.asarray(got["alpha"])
                                        != ref["alpha"]))
     else:
+        # random selection (fedavg) is held to its count; the control has
+        # no selection of its own and is given the round's
+        alpha = got.get("alpha", rec["pending"].alpha)
         want = max(1, int(0.3 * len(rec["pending"].fleet)))
-        n["plan_alpha"] = float(abs(int(np.sum(got["alpha"])) - want))
+        n["plan_alpha"] = float(abs(int(np.sum(alpha)) - want))
     gp, rp = got["plan"], ref["plan"]
     if len(rp["l"]):
         n["plan_gap"] = float(max(np.max(np.abs(gp["l"] - rp["l"])),

@@ -7,18 +7,19 @@ rolled by up to 3 pixels plus Gaussian noise (sigma 0.25), clipped to
 [-1, 1]. Labels are uniform over the classes. The same (dataset, n, seed)
 gives the same arrays in every process.
 
-Weights: the client ResNet-18 and the RSU's DDPM UNet, each made on the
-device by one jitted call from the seed, in float32 as they are served.
+Weights: the client model and the RSU's generator, of the architectures
+the config names (`archs/`), both made on the device by one jitted call
+from the seed, in float32 as they are served.
 """
 from __future__ import annotations
 
 import zlib
-from functools import lru_cache, partial
+from functools import lru_cache
 
 import jax
 import numpy as np
 
-from reference import resnet, unet
+from chipbench import cells
 
 IMG = 32
 CLASSES = {"cifar10": 10, "cifar100": 100, "gtsrb": 43}
@@ -74,16 +75,15 @@ def make_dataset(name: str, n: int, seed: int = 0, noise: float = 0.25,
     return imgs, labels
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3))
-def _init_all(key, num_classes: int, width_mult: float, unet_base: int):
-    k1, k2 = jax.random.split(key)
-    return (resnet.init(k1, num_classes, width_mult),
-            unet.init(k2, num_classes, unet_base))
+def make_weights(seed: int, num_classes: int, model: dict, generator: dict):
+    """(client params, generator params) from the seed, on the device;
+    `model` and `generator` are the config's groups of those names."""
+    client, gen = cells.arch(model["arch"]), cells.arch(generator["arch"])
 
-
-def make_weights(seed: int, num_classes: int, width_mult: float,
-                 unet_base: int):
-    """(client CNN params, UNet params) from the seed, on the device."""
+    def _init_all(key):
+        k1, k2 = jax.random.split(key)
+        return (client.init(k1, num_classes, model),
+                gen.init(k2, num_classes, generator))
     key = jax.random.fold_in(jax.random.PRNGKey(0), seed % (2 ** 31))
     key = jax.random.fold_in(key, seed // (2 ** 31))
-    return _init_all(key, num_classes, float(width_mult), int(unet_base))
+    return jax.jit(_init_all)(key)

@@ -9,7 +9,7 @@ import time
 import jax
 import numpy as np
 
-from chipbench import build, capture, check, tracing, warm
+from chipbench import build, capture, cells, check, tracing, warm
 
 WARM_ROUNDS = 2     # rounds run at the end of the set-up
 TRACE_ROUNDS = 5    # steady rounds the profiler traces with --trace 1
@@ -58,7 +58,7 @@ class Session:
             now = time.perf_counter()
             self.phases[name] = now - clock[0]
             clock[0] = now
-        self.runner, self.unet_params = build.build_runner(
+        self.runner, self.gen_params = build.build_runner(
             self.cfgd, celld, self.seed, obs=obs)
         runner = self.runner
         lap("build")
@@ -157,11 +157,12 @@ class Session:
 
     # ------------------------------------------------------------------
     def check_ctx(self) -> dict:
-        r = self.runner
+        r, model, gen = self.runner, self.cfgd["model"], self.cfgd["generator"]
         return {"cfg": build.cfg_dict(r), "strategy": self.celld["strategy"],
                 "model_bits": r.model_bits, "t_image": r.svc.t_per_image,
-                "unet_params": self.unet_params, "seed": self.seed,
-                "timesteps": self.cfgd["generator"]["timesteps"],
+                "client": cells.arch(model["arch"]),
+                "generator": cells.arch(gen["arch"]), "gen_spec": gen,
+                "gen_params": self.gen_params, "seed": self.seed,
                 "sampler_steps": self.celld["sampler_steps"],
                 "test": (r.test_imgs, r.test_labels)}
 

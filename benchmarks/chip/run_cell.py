@@ -98,7 +98,7 @@ def run(args, bench, celld, cfgd, peak) -> dict:
     """Set-up, window, metrics and comparison of one run; returns the
     result object. Checks no device (`main` does)."""
     import jax
-    from chipbench import cells, check, flops, tracing
+    from chipbench import cells, check, tracing
     from chipbench.session import Session
 
     devs = jax.devices()
@@ -125,7 +125,9 @@ def run(args, bench, celld, cfgd, peak) -> dict:
     if args.trace:
         red = tracing.reduce(tracing.events(str(TRACE_DIR)))
         ctx = {"red": red, "config": cfgd, "cell": celld, "peak": peak,
-               "flops": flops, "module_time": tracing.module_time,
+               "client": cells.arch(cfgd["model"]["arch"]),
+               "generator": cells.arch(cfgd["generator"]["arch"]),
+               "module_time": tracing.module_time,
                "rsu_steps_factor": s.runner.cfg.rsu_steps_factor,
                "rounds": [{"k": lg.selected, "b_gen": lg.b_gen}
                           for lg in s.traced]}

@@ -80,9 +80,13 @@ def test_entry_keys():
 def test_config_files_load(name):
     assert NAME.match(name)
     c = cells.config(name)
-    m = c["model"]
-    assert m["stage_widths"] == [64, 128, 256, 512] and m["width_mult"] == 1.0
-    assert c["generator"]["t_image"] > 0 and NAME.match(c["run"]["dataset"])
+    m, g = c["model"], c["generator"]
+    # the architecture files build the topology the config states, and
+    # refuse another (tests/test_bench_archs.py)
+    cells.arch(m["arch"]).runner_fields(m)
+    cells.arch(g["arch"]).program(m["num_classes"], g)
+    assert m["width_mult"] == 1.0                   # published widths
+    assert g["t_image"] > 0 and NAME.match(c["run"]["dataset"])
 
 
 @pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])

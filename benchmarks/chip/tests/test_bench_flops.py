@@ -1,10 +1,23 @@
-"""The FLOP and parameter counts the roofline and MFU metrics use,
-against the round loop's own models and XLA's cost analysis."""
+"""The FLOP and parameter counts the roofline and MFU metrics use (the
+architecture files under archs/), against the round loop's own models
+and XLA's cost analysis."""
 import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import flops
+from chipbench import cells
+
+CFG = cells.config("resnet18-cifar10")
+
+
+def client(width=1.0):
+    m = dict(CFG["model"], width_mult=width)
+    return cells.arch(m["arch"]), m
+
+
+def generator(base):
+    g = dict(CFG["generator"], base_width=base)
+    return cells.arch(g["arch"]), g
 
 
 @pytest.mark.parametrize("width,classes", [(1.0, 10), (1.0, 43),
@@ -15,15 +28,19 @@ def test_resnet_param_count_matches_round_loop(width, classes):
     cfg = CNNConfig(name="t", num_classes=classes, width_mult=width)
     shapes = jax.eval_shape(lambda k: init_cnn(k, cfg), jax.random.key(0))
     n = sum(x.size for x in jax.tree.leaves(shapes))
-    assert n == flops.resnet_params(width, classes)
+    arch, m = client(width)
+    assert n == arch.param_count(classes, m)
 
 
-def test_unet_param_count_matches_round_loop():
-    from repro.diffusion.unet import init_unet
-    shapes = jax.eval_shape(lambda k: init_unet(k, 10, base=16),
-                            jax.random.key(0))
+@pytest.mark.parametrize("base", [16, 32])
+def test_unet_param_count_matches_round_loop(base):
+    """The program's own generator (`program_layout`: the program's init
+    of the model `program` builds) at the served base width and at twice
+    it."""
+    arch, g = generator(base)
+    shapes = arch.program_layout(10, g)
     assert sum(x.size for x in jax.tree.leaves(shapes)) == \
-        flops.unet_params(16, 10)
+        arch.param_count(10, g)
 
 
 def _xla_flops(fn, *args):
@@ -33,7 +50,8 @@ def _xla_flops(fn, *args):
     return float(cost["flops"])
 
 
-def test_resnet_train_step_flops_match_xla():
+@pytest.mark.parametrize("width", [1.0, 0.5])
+def test_resnet_train_step_flops_match_xla(width):
     """One SGD step of the round loop's ResNet-18 on a batch of 8.
 
     XLA counts every operation; the model count leaves out GroupNorm,
@@ -44,27 +62,30 @@ def test_resnet_train_step_flops_match_xla():
     from repro.configs.genfv_cifar import cnn_config
     from repro.fl.client import local_sgd_steps
     from repro.models.cnn import init_cnn
-    cfg = cnn_config("cifar10", 1.0)
+    cfg = cnn_config("cifar10", width)
     params = jax.eval_shape(lambda k: init_cnn(k, cfg), jax.random.key(0))
     b = 8
     imgs = jax.ShapeDtypeStruct((1, b, 32, 32, 3), jnp.float32)
     labels = jax.ShapeDtypeStruct((1, b), jnp.int32)
     xla = _xla_flops(lambda p, x, y: local_sgd_steps(p, cfg, x, y, 1, 0.05),
                      params, imgs, labels)
-    model = b * flops.resnet_train_flops(1.0, 10)
+    arch, m = client(width)
+    model = b * arch.train_flops(10, m)
     assert model <= xla <= 1.08 * model, xla / model
 
 
-def test_unet_step_flops_match_xla():
-    """One UNet forward of the served generator (base width 16) on 8
-    images. Same rule and tolerance as the ResNet step: the model count
-    leaves out GroupNorm, SiLU and the softmax."""
+@pytest.mark.parametrize("base", [16, 32])
+def test_unet_step_flops_match_xla(base):
+    """One UNet forward of the served generator (base width 16, and 32)
+    on 8 images. Same rule and tolerance as the ResNet step: the model
+    count leaves out GroupNorm, SiLU and the softmax."""
     from repro.diffusion.unet import init_unet, unet_apply
-    params = jax.eval_shape(lambda k: init_unet(k, 10, base=16),
+    params = jax.eval_shape(lambda k: init_unet(k, 10, base=base),
                             jax.random.key(0))
     b = 8
     x = jax.ShapeDtypeStruct((b, 32, 32, 3), jnp.float32)
     t = jax.ShapeDtypeStruct((b,), jnp.int32)
     xla = _xla_flops(unet_apply, params, x, t, t)
-    model = b * flops.unet_step_flops(16)
+    arch, g = generator(base)
+    model = b * arch.step_flops(10, g)
     assert model <= xla <= 1.08 * model, xla / model

@@ -5,7 +5,7 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import cells, flops, tracing
+from chipbench import cells, tracing
 
 
 # two rounds, [0, 1000) and [1000, 2500) ns: host spans, two device
@@ -35,8 +35,11 @@ def test_hand_trace():
 
 def _ctx(red, cell):
     celld = cells.cell(cell)
-    return {"red": red, "config": cells.config(celld["config"]),
-            "cell": celld, "flops": flops, "module_time": tracing.module_time,
+    cfgd = cells.config(celld["config"])
+    return {"red": red, "config": cfgd, "cell": celld,
+            "client": cells.arch(cfgd["model"]["arch"]),
+            "generator": cells.arch(cfgd["generator"]["arch"]),
+            "module_time": tracing.module_time,
             "peak": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
             "rsu_steps_factor": 4,
             "rounds": [{"k": 8, "b_gen": 900}, {"k": 8, "b_gen": 900}]}
@@ -53,10 +56,29 @@ def test_hand_trace_metrics():
     assert read["fleet.aggregate_s"] == pytest.approx(450e-9)
     assert read["device.idle_share"] == pytest.approx(60.0)
     assert read["loop.host_s"] == 0.0
-    train = flops.resnet_train_flops(1.0, 10)
+    m = ctx["config"]["model"]
+    train = ctx["client"].train_flops(10, m)
     want = 100 * 2 * 8 * 4 * 64 * train / 197e12 / 700e-9
     assert cells.reader("kernel.fleet_step_roofline")(ctx) == \
         pytest.approx(want)
+
+
+@pytest.mark.parametrize("cell", ["c10.genfv.highway", "c10.fedavg.highway"])
+def test_hand_trace_mfu(cell):
+    """Two rounds of K=8 with b_gen 900 planned: a cell that generates
+    counts the RSU's 4 x 4 x 64 images and 900 x 50 denoising steps a
+    round, one that does not (fedavg still plans b_gen) neither."""
+    ctx = _ctx(tracing.reduce(HAND), cell)
+    m, g = ctx["config"]["model"], ctx["config"]["generator"]
+    train, fwd = ctx["client"].train_flops(10, m), \
+        ctx["client"].fwd_flops(10, m)
+    gen = cell.split(".")[1] == "genfv"
+    per_round = ((8 * 4 * 64 + (4 * 4 * 64 if gen else 0)) * train
+                 + (900 * 50 * ctx["generator"].step_flops(10, g)
+                    if gen else 0)
+                 + 512 * fwd)
+    want = 100 * 2 * per_round / (2500e-9 * 197e12)
+    assert cells.reader("round.mfu")(ctx) == pytest.approx(want)
 
 
 def test_reader_finds_nothing_returns_none():

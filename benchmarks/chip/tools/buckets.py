@@ -8,8 +8,9 @@
 
 Runs the cell's round loop on the CPU for `--rounds` rounds per seed with
 everything that decides selection and planning as on the chip: the same
-seeded labels and partition, the same world, model_bits of the full-width
-ResNet-18 and the configuration's pinned t0. Nothing is trained: the
+seeded labels and partition, the same world, model_bits of the client
+model at its published widths (its architecture file's parameter count)
+and the configuration's pinned t0. Nothing is trained: the
 fleet dispatch returns the params it is given, the RSU's training draws
 its batch indices from the round loop's generator (h x B with
 replacement, as `fl/client.py::client_update` does) and trains nothing,
@@ -39,7 +40,6 @@ from pathlib import Path
 HERE = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(HERE), str(HERE.parents[1] / "src")]
 
-SIM_WIDTH = 0.0625
 FLOOR_ROUND_S = 0.1
 
 
@@ -80,7 +80,7 @@ def _untrained(runner):
 def sim_runner(cell_name: str, seed: int, rounds: int):
     """The cell's round loop with the device work taken out (`_untrained`)
     and the round loop's seed `seed`."""
-    from chipbench import cells, data, flops
+    from chipbench import cells, data
     from repro.configs.genfv_cifar import genfv_config
     from repro.fl import GenFVRunner, RunConfig
     from repro.gen.calib import MeasuredService
@@ -88,13 +88,14 @@ def sim_runner(cell_name: str, seed: int, rounds: int):
     celld = cells.cell(cell_name)
     cfgd = cells.config(celld["config"])
     m, r, g = cfgd["model"], cfgd["run"], cfgd["generator"]
-    bits = 32.0 * flops.resnet_params(m["width_mult"], m["num_classes"])
+    client = cells.arch(m["arch"])
+    bits = 32.0 * client.param_count(m["num_classes"], m)
     run = RunConfig(dataset=r["dataset"], alpha=celld["alpha"],
                     strategy=celld["strategy"], train_size=r["train_size"],
-                    test_size=r["test_size"], width_mult=SIM_WIDTH,
+                    test_size=r["test_size"], **client.runner_fields(m),
                     seed=seed, model_bits=bits, scenario=celld["scenario"],
-                    planner="jax", generator="ddpm",
-                    sampler_steps=celld["sampler_steps"], rounds=rounds)
+                    planner="jax", sampler_steps=celld["sampler_steps"],
+                    rounds=rounds)
     runner = GenFVRunner(
         run, fl_cfg=genfv_config(r["dataset"], celld["alpha"], **cfgd["fl"]),
         generator=_Blank(), dataset_fn=data.make_dataset,
